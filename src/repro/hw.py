@@ -1,8 +1,9 @@
 """TPU v5e hardware constants used by the roofline cost model, the latency
 predictor's ground-truth simulator, and the dry-run roofline analysis.
 
-The container is CPU-only; TPU v5e is the *target*. All perf reasoning in this
-repo (costmodel, roofline, predictor fits) is derived from these constants.
+These are published peaks, not measurements. The cost model, roofline and
+predictor fits derive from them; nothing here says how fast the real path
+(``chip_smoke.py``, ``launch/serve.py``) runs on a chip.
 """
 
 from __future__ import annotations

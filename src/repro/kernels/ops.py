@@ -20,6 +20,8 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
+    """Interpret mode off the TPU (CPU tests). On the chip the kernels lower
+    through Mosaic; chip_smoke.py checks the compiled program for it."""
     return jax.default_backend() != "tpu"
 
 
@@ -36,17 +38,27 @@ def decode_attention(q, kc, vc, kv_pos, positions, window: int = 0,
                      scale=None, page_tokens: int = 64, scales=None):
     """Dense-cache adapter matching attention.decode_attn_ref's signature so
     model.decode_step can swap the kernel in: treats each slot's contiguous
-    cache as pages of `page_tokens`.
+    cache as pages of `page_tokens`. A full-attention slot holds positions
+    0..positions[b] in order, so its valid length is positions + 1.
 
     q: (B, H, hd); kc/vc: (B, S, KV, hd); kv_pos: (B, S); positions: (B,).
+    Raises on what the kernel cannot take (no silent reference fallback):
+    ring-buffered windowed caches, a cache length that is not a multiple of
+    `page_tokens`, and int8 caches with per-token scales.
     """
     B, S, KV, hd = kc.shape
-    if window > 0 or S % page_tokens:
-        # ring-buffered (SWA) caches keep arbitrary positions per slot —
-        # fall back to the reference path (kernel targets the paged pool).
-        from repro.models.attention import decode_attn_ref
-        return decode_attn_ref(q, kc, vc, kv_pos, positions, window,
-                               scale=scale)
+    if window > 0:
+        raise ValueError(
+            f"decode_attention kernel has no sliding-window variant "
+            f"(window={window}); windowed layers use decode_attn_ref")
+    if S % page_tokens:
+        raise ValueError(
+            f"cache length {S} is not a multiple of the kernel page size "
+            f"{page_tokens}")
+    if scales is not None and scales[0] is not None:
+        raise ValueError("decode_attention kernel reads bf16/f32 pages; "
+                         "int8 caches with per-token scales use "
+                         "decode_attn_ref")
     n_pages = S // page_tokens
     k_pages = kc.reshape(B * n_pages, page_tokens, KV, hd)
     v_pages = vc.reshape(B * n_pages, page_tokens, KV, hd)

@@ -265,7 +265,7 @@ def attn_decode(p: Params, x, positions, cache: Dict, cfg: ModelConfig, *,
     k = L.apply_rope(k, positions[:, None], cfg.rope_theta)
     cache = _cache_write_bulk(cache, k, v, positions[:, None], window)
     kc, vc, kv_pos = cache["k"], cache["v"], cache["kv_pos"]
-    if decode_attn_fn is None or "k_scale" in cache:
+    if decode_attn_fn is None:
         decode_attn_fn = decode_attn_ref
     o = decode_attn_fn(q[:, 0], kc, vc, kv_pos, positions, window,
                        scales=(cache.get("k_scale"), cache.get("v_scale")))

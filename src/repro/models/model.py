@@ -533,7 +533,11 @@ def decode_step(params, cfg: ModelConfig, tokens, positions, cache, *,
     pre_kinds, scan_kind, n_scan, post_kinds = _plan(cfg)
     x = L.embed(tokens[:, None], params["embed"])     # (B, 1, d)
     x = constrain(x, ("batch", None, None))
-    if decode_attn_fn is None and use_kernels:
+    # The paged kernel covers full-attention bf16 caches only: ring-buffered
+    # (windowed) layers and int8 caches have no kernel yet, so they keep the
+    # reference decode_attn_ref.
+    if decode_attn_fn is None and use_kernels and not cfg.kv_quant and \
+            _layer_window(cfg, scan_kind) == 0:
         from repro.kernels import ops as kops
         decode_attn_fn = kops.decode_attention
 

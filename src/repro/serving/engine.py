@@ -3,15 +3,15 @@
 This is the decode *instance* of the disaggregated deployment (paper §2.1):
 prefill runs out-of-band (a separate instance; here a jitted prefill call),
 decode proceeds in rounds over a fixed slot array with continuous batching.
-Harli's scheduler hooks the round boundary (``round_hook``) to co-schedule
-finetune layer-units; the discrete-event counterpart used for paper-scale
-experiments lives in core/simulator.py.
+Harli's scheduler hooks the round boundary (``decode_round(step)``) to
+co-schedule finetune layer-units; the discrete-event counterpart used for
+paper-scale experiments lives in core/simulator.py.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
+import functools
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -54,9 +54,12 @@ class ServingEngine:
                                       max_slots, -(-s_max // page_tokens))
         self.slots: List[Optional[Request]] = [None] * max_slots
         self.last_token = np.zeros((max_slots,), np.int32)
+        # cache entries each slot's prefill wrote: frontend + prompt tokens
+        self.prefilled = np.zeros((max_slots,), np.int32)
 
         self._prefill = jax.jit(
             lambda p, b, c: MD.prefill(p, cfg, b, c))
+        self._insert = jax.jit(_insert_slot, donate_argnums=0)
         self._decode = jax.jit(
             lambda p, t, q, c: MD.decode_step(p, cfg, t, q, c,
                                               use_kernels=use_kernels))
@@ -65,18 +68,22 @@ class ServingEngine:
     def try_admit(self, req: Request, prompt_tokens: np.ndarray,
                   extras: Optional[Dict] = None) -> bool:
         slot = next((i for i, s in enumerate(self.slots) if s is None), None)
-        if slot is None or not self.pages.admit(slot, req.prompt_len):
+        n_front = (len(extras["frontend"]) if extras and "frontend" in extras
+                   and self.cfg.frontend != "none" else 0)
+        if slot is None or not self.pages.admit(slot,
+                                                n_front + req.prompt_len):
             self.metrics.rejected_admissions += 1
             return False
         req.slot, req.phase = slot, Phase.PREFILLING
         self.slots[slot] = req
+        self.prefilled[slot] = n_front + req.prompt_len
         batch = {"tokens": jnp.asarray(prompt_tokens[None, :])}
         if extras:
             batch.update({k: jnp.asarray(v)[None] for k, v in extras.items()})
         one_cache = MD.init_cache(self.cfg, 1, self.s_max,
                                   enc_len=self.enc_len)
         logits, one_cache = self._prefill(self.params, batch, one_cache)
-        self._insert_slot_cache(slot, one_cache)
+        self.cache = self._insert(self.cache, one_cache, jnp.int32(slot))
         tok = int(jnp.argmax(logits[0]))
         self.last_token[slot] = tok
         req.generated = 1
@@ -85,28 +92,31 @@ class ServingEngine:
         self.metrics.tokens_out += 1
         return True
 
-    def _insert_slot_cache(self, slot: int, one_cache) -> None:
-        def put(dst, src):
-            return dst.at[slot].set(src[0])
-        self.cache = jax.tree.map(put, self.cache, one_cache)
-
     # ------------------------------------------------------------- rounds --
     def active_requests(self) -> List[Request]:
         return [r for r in self.slots if r is not None and
                 r.phase == Phase.DECODING]
 
-    def decode_round(self) -> Dict[int, int]:
-        """One decode step over all active slots. Returns {rid: token}."""
+    def decode_round(self, step: Optional[Callable] = None) -> Dict[int, int]:
+        """One decode step over all active slots. Returns {rid: token}.
+
+        ``step(tokens, positions, cache) -> (logits, cache)`` replaces the
+        engine's own decode program; the co-located runner passes its fused
+        decode + k finetune units variant here."""
         active = [(i, r) for i, r in enumerate(self.slots)
                   if r is not None and r.phase == Phase.DECODING]
         if not active:
             return {}
+        if step is None:
+            step = functools.partial(self._decode, self.params)
         tokens = jnp.asarray(self.last_token)
         positions = np.zeros((self.max_slots,), np.int32)
         for i, r in active:
-            positions[i] = r.context_len  # index of the token being written
-        logits, self.cache = self._decode(self.params, tokens,
-                                          jnp.asarray(positions), self.cache)
+            # the newest token (not yet in the cache) goes right after the
+            # prefilled entries and the generated - 1 decoded before it, so
+            # each slot's cache stays contiguous from 0
+            positions[i] = self.prefilled[i] + r.generated - 1
+        logits, self.cache = step(tokens, jnp.asarray(positions), self.cache)
         next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
 
         out: Dict[int, int] = {}
@@ -120,7 +130,7 @@ class ServingEngine:
             self.metrics.tokens_out += 1
             out[r.rid] = int(next_tokens[i])
             if r.generated >= r.max_new_tokens or \
-                    r.context_len >= self.s_max - 1:
+                    self.prefilled[i] + r.generated >= self.s_max - 1:
                 r.phase = Phase.DONE
                 self.pages.release(r.slot)
                 self.slots[i] = None
@@ -159,3 +169,15 @@ class ServingEngine:
             return {"enc_frames": self.rng.normal(
                 size=(max(self.enc_len, 1), cfg.d_model)).astype(np.float32)}
         return None
+
+
+def _insert_slot(cache, one_cache, slot):
+    """Write a one-request cache into batch row ``slot``. The scanned stack
+    carries the layer axis first, so its batch axis is 1; the unrolled
+    pre/post layers have batch at axis 0."""
+    def at(axis):
+        return lambda dst, src: jax.lax.dynamic_update_slice_in_dim(
+            dst, src.astype(dst.dtype), slot, axis)
+    return {"pre": jax.tree.map(at(0), cache["pre"], one_cache["pre"]),
+            "scan": jax.tree.map(at(1), cache["scan"], one_cache["scan"]),
+            "post": jax.tree.map(at(0), cache["post"], one_cache["post"])}

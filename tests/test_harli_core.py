@@ -1,13 +1,16 @@
 """HARLI core: two-stage predictor accuracy bands, QoS scheduler behaviour,
 colocated-step equivalence, simulator end-to-end (paper headline direction)."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.colocation import ColocatedRunner
+from repro.core.colocation import ColocatedRunner, make_ft_only_step
 from repro.core.costmodel import CostModel, InstanceSpec
 from repro.core.predictor import TwoStageLatencyPredictor
 from repro.core.scheduler import QoSScheduler, SchedulerConfig
@@ -110,6 +113,39 @@ def test_colocated_step_equivalence(key):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for a, b in zip(jax.tree.leaves(ft_f), jax.tree.leaves(ft_s)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("program", ["colocated", "ft_only"])
+def test_weights_are_program_arguments(program, key):
+    """Every weight reaches the co-located and finetune-only programs as an
+    argument: a closed-over array would be lowered as a constant, one copy
+    per quantum variant."""
+    cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=64,
+                      num_heads=4, num_kv_heads=2, d_ff=96, vocab_size=128,
+                      lora=LoRAConfig(rank=4))
+    params = MD.init_params(cfg, key)
+    pc = P.PeftConfig(micro_batch=2, seq_len=8, accum=1)
+    pf = Prefetcher(SyntheticCorpus(DataConfig(128, 8, 2)).batches(), 2)
+    ft0 = P.init_ft_state(cfg, pc, params, key, pf.stacked())
+    if program == "colocated":
+        runner = ColocatedRunner(cfg, params, cfg, params, pc, k_max=2)
+        tok = jnp.zeros((3,), jnp.int32)
+        cache0 = MD.init_cache(cfg, 3, 32)
+        lowered = runner.lower(2, tok, tok, cache0, ft0)
+        args = (params, params, tok, tok, cache0, ft0)
+    else:
+        burst = make_ft_only_step(cfg, params, pc, units=2)
+        lowered = burst.func.lower(*burst.args, ft0)
+        args = (params, ft0)
+    text = lowered.as_text()
+    sig = re.search(r"func\.func public @main\((.*?)\)\s*->", text, re.S)
+    assert len(re.findall(r"%arg\d+:", sig.group(1))) == \
+        len(jax.tree.leaves(args))
+    smallest = min(x.size for x in jax.tree.leaves(params) if x.ndim > 1)
+    for shape in re.findall(r"stablehlo\.constant dense<[^\n]*?>\s*:\s*"
+                            r"tensor<([^>]*)>", text):
+        assert math.prod(int(d) for d in shape.split("x")[:-1]) < smallest, \
+            shape
 
 
 @pytest.mark.slow

@@ -11,11 +11,13 @@ try:
 except ImportError:                          # tier-1 container has none
     from _hyp_fallback import given, settings, strategies as st
 
+from repro.configs import smoke_config
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import paged_decode_attention
 from repro.kernels.lora_matmul import lora_matmul as lora_kernel
 from repro.kernels.ref import (lora_matmul_ref, paged_decode_attention_ref,
                                ssd_sequential_ref)
+from repro.models import model as MD
 from repro.models.ssm import ssd_chunked
 
 
@@ -59,6 +61,45 @@ def test_paged_decode_attention_hypothesis(B, npg, ptok, seed):
     out = paged_decode_attention(q, kp, vp, pt, lengths, interpret=True)
     expect = paged_decode_attention_ref(q, kp, vp, pt, lengths)
     np.testing.assert_allclose(out, expect, atol=3e-5, rtol=3e-5)
+
+
+def test_decode_step_kernel_matches_reference(key):
+    """model.decode_step through the kernel equals the decode_attn_ref path
+    on the same cache: GQA with 2 q heads per KV head, a cache of two
+    64-token pages, slots that end inside the second page, exactly at its
+    start, and inside the first. Float32 weights and cache, so only the
+    order of the sums differs."""
+    cfg = smoke_config("qwen2.5-7b")
+    assert cfg.q_per_kv > 1
+    params = MD.init_params(cfg, key, dtype=jnp.float32)
+    B, S, plen = 3, 128, 100
+    cache = MD.init_cache(cfg, B, S, dtype=jnp.float32)
+    toks = jax.random.randint(key, (B, plen), 0, cfg.vocab_size)
+    _, cache = MD.prefill(params, cfg, {"tokens": toks}, cache)
+    positions = jnp.array([plen, 64, 7], jnp.int32)
+    out = {uk: MD.decode_step(params, cfg, toks[:, 0], positions, cache,
+                              use_kernels=uk)
+           for uk in (True, False)}
+    for a, b in zip(jax.tree.leaves(out[True]), jax.tree.leaves(out[False])):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("case", ["unaligned_length", "int8_scales",
+                                  "window"])
+def test_decode_attention_rejects_what_the_kernel_cannot_take(case):
+    B, H, KV, hd = 2, 4, 2, 16
+    S = 100 if case == "unaligned_length" else 128
+    dtype = jnp.int8 if case == "int8_scales" else jnp.bfloat16
+    kc = jnp.zeros((B, S, KV, hd), dtype)
+    kw = {}
+    if case == "int8_scales":
+        kw["scales"] = (jnp.ones((B, S)), jnp.ones((B, S)))
+    if case == "window":
+        kw["window"] = 32
+    with pytest.raises(ValueError):
+        ops.decode_attention(jnp.zeros((B, H, hd), jnp.bfloat16), kc, kc,
+                             jnp.zeros((B, S), jnp.int32),
+                             jnp.zeros((B,), jnp.int32), **kw)
 
 
 # --------------------------------------------------------- lora matmul ----

@@ -38,6 +38,37 @@ def test_engine_continuous_batching(tiny):
     assert all(r.phase.value == "done" for r in reqs)
 
 
+def _assert_no_holes(cfg, params, n_front):
+    eng = ServingEngine(cfg, params, max_slots=2, s_max=64)
+    reqs = [Request(rid=i, arrival=0.0, prompt_len=5 + 3 * i,
+                    max_new_tokens=6) for i in range(2)]
+    eng.run_trace(reqs)
+    kv_pos = np.asarray(eng.cache["scan"]["kv_pos"][0])
+    for r in reqs:
+        # frontend + prompt entries, then the decoded tokens; the last token
+        # is returned only
+        n = n_front + r.context_len - 1
+        np.testing.assert_array_equal(kv_pos[r.slot, :n], np.arange(n))
+        assert (kv_pos[r.slot, n:] == -1).all()
+
+
+def test_engine_cache_has_no_holes(tiny):
+    """Each decode round writes the newest token right after the cached
+    context, so a slot holds positions 0..n-1 in order (the decode kernel
+    reads a slot as its first positions + 1 entries)."""
+    _assert_no_holes(*tiny, n_front=0)
+
+
+def test_engine_cache_has_no_holes_behind_frontend():
+    """With a vision frontend, prefill caches the patch embeddings before
+    the prompt; decode continues after both."""
+    cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=64,
+                      num_heads=4, num_kv_heads=2, d_ff=96, vocab_size=256,
+                      frontend="vision", frontend_tokens=4)
+    _assert_no_holes(cfg, MD.init_params(cfg, jax.random.PRNGKey(0)),
+                     n_front=4)
+
+
 def test_engine_memory_pressure_rejects(tiny):
     cfg, params = tiny
     eng = ServingEngine(cfg, params, max_slots=4, s_max=64, num_pages=4,

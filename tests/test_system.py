@@ -2,84 +2,35 @@
 config — the whole Harli stack (engine + colocated runner + scheduler +
 predictor) driving actual XLA programs."""
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from repro.configs import smoke_config, get_config
-from repro.core.colocation import ColocatedRunner, make_ft_only_step
-from repro.core.costmodel import CostModel, InstanceSpec
-from repro.core.predictor import TwoStageLatencyPredictor
-from repro.core.scheduler import QoSScheduler, SchedulerConfig
+from repro.configs import smoke_config
+from repro.core.colocation import make_ft_only_step
+from repro.launch import serve
 from repro.models import model as MD
-from repro.serving.engine import ServingEngine
-from repro.serving.request import Phase, Request
+from repro.serving.request import Phase
 from repro.training import peft as P
 from repro.training.data import DataConfig, Prefetcher, SyntheticCorpus
-from repro.training.optimizer import AdamWConfig
 
 
 @pytest.mark.slow
-def test_colocated_serving_end_to_end(key):
-    cfg = smoke_config("llama3-8b")
-    params = MD.init_params(cfg, key)
-    eng = ServingEngine(cfg, params, max_slots=3, s_max=96)
-
-    pc = P.PeftConfig(micro_batch=2, seq_len=16, accum=1,
-                      opt=AdamWConfig(lr=1e-3))
-    pf = Prefetcher(SyntheticCorpus(
-        DataConfig(cfg.vocab_size, 16, 2, seed=0)).batches(), pc.n_stage)
-    ft_state = P.init_ft_state(cfg, pc, params, key, pf.stacked())
-    runner = ColocatedRunner(cfg, params, cfg, params, pc, k_max=4,
-                             donate=False)
-    pred = TwoStageLatencyPredictor(k_max=4)
-    pred.fit_from_costmodel(CostModel(get_config("llama3-8b"),
-                                      InstanceSpec(tp=2)))
-    sched = QoSScheduler(pred, SchedulerConfig(k_max=4))
-
-    rng = np.random.default_rng(0)
-    reqs = [Request(rid=i, arrival=0.0, prompt_len=int(rng.integers(6, 14)),
-                    max_new_tokens=5) for i in range(5)]
-    qi, rounds, units = 0, 0, 0
-    while rounds < 200:
-        while qi < len(reqs):
-            toks = rng.integers(0, cfg.vocab_size, reqs[qi].prompt_len,
-                                dtype=np.int32)
-            if eng.try_admit(reqs[qi], toks):
-                qi += 1
-            else:
-                break
-        active = eng.active_requests()
-        if not active and qi >= len(reqs):
-            break
-        bs = len(active)
-        ctx = sum(r.context_len for r in active) / max(bs, 1)
-        k = sched.pick(bs, ctx, ft_ready=True, ft_units_available=4).k
-        tokens = jnp.asarray(eng.last_token)
-        positions = np.zeros((eng.max_slots,), np.int32)
-        for i, r in enumerate(eng.slots):
-            if r is not None:
-                positions[i] = r.context_len
-        logits, eng.cache, ft_state = runner.run_round(
-            k, tokens, jnp.asarray(positions), eng.cache, ft_state)
-        units += k
-        nt = np.asarray(jnp.argmax(logits, -1), np.int32)
-        for i, r in list(enumerate(eng.slots)):
-            if r is None:
-                continue
-            eng.pages.extend(r.slot, 1)
-            eng.last_token[i] = nt[i]
-            r.generated += 1
-            if r.generated >= r.max_new_tokens:
-                r.phase = Phase.DONE
-                eng.pages.release(r.slot)
-                eng.slots[i] = None
-        rounds += 1
-
-    assert all(r.phase == Phase.DONE for r in reqs)
-    assert units > 0, "no finetune units were co-scheduled"
+@pytest.mark.parametrize("use_kernels", [False, True])
+def test_colocated_serving_end_to_end(use_kernels, monkeypatch):
+    """launch/serve.run, the entry point chip_smoke.py drives on a TPU, at
+    smoke size: every request finishes with all of its tokens and the
+    fused programs make finetune progress (kernels in interpret mode)."""
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+    res = serve.run("llama3-8b", smoke=True, requests=5, slots=3, s_max=128,
+                    prompt_lens=range(6, 14), new_tokens=(5,),
+                    colocate=True, k_max=4, ft_seq=16,
+                    use_kernels=use_kernels)
+    reqs = res.requests
+    assert all(r.phase == Phase.DONE and r.generated == 5 for r in reqs)
+    assert res.engine.metrics.tokens_out == 5 * len(reqs)
+    assert sorted(res.compiled) == [0, 1, 2, 3, 4]
+    assert res.units_done > 0, "no finetune units were co-scheduled"
     # finetune made real progress inside the fused programs
+    ft_state = res.ft_state
     assert int(ft_state["iter"]) >= 1 or int(ft_state["unit_idx"]) > 0
 
 
