@@ -18,6 +18,7 @@ from typing import Callable, Dict, Optional
 
 import jax
 
+from repro import obs
 from repro.models import model as MD
 from repro.models.config import ModelConfig
 from repro.training import peft as P
@@ -52,8 +53,10 @@ class ColocatedRunner:
         use_kernels = self._use_kernels
 
         def step(params_inf, params_ft, tokens, positions, cache, ft_state):
-            logits, cache = MD.decode_step(params_inf, cfg, tokens, positions,
-                                           cache, use_kernels=use_kernels)
+            with jax.named_scope("decode"):
+                logits, cache = MD.decode_step(params_inf, cfg, tokens,
+                                               positions, cache,
+                                               use_kernels=use_kernels)
             unit_step = P.make_unit_step(cfg_ft, pc, params_ft)
             ft_state = P.run_units(unit_step, ft_state, k)
             return logits, cache, ft_state
@@ -67,11 +70,13 @@ class ColocatedRunner:
     def run_round(self, k: int, tokens, positions, cache, ft_state):
         """Run variant k, compiling it first if precompile did not."""
         k = self._clamp(k)
-        if k not in self._compiled:
-            self._compiled[k] = self.lower(
-                k, tokens, positions, cache, ft_state).compile()
-        return self._compiled[k](self._params_inf, self._params_ft, tokens,
-                                 positions, cache, ft_state)
+        with obs.span("colo.round", k=k):
+            if k not in self._compiled:
+                with obs.span("colo.compile", k=k):
+                    self._compiled[k] = self.lower(
+                        k, tokens, positions, cache, ft_state).compile()
+            return self._compiled[k](self._params_inf, self._params_ft,
+                                     tokens, positions, cache, ft_state)
 
     def lower(self, k: int, tokens, positions, cache, ft_state):
         """Lower variant k for these arguments (arrays or shape structs;

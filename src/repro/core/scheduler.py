@@ -12,8 +12,8 @@ defaults to the paper's behaviour when predictions are accurate).
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
 
+from repro import obs
 from repro.core.predictor import TwoStageLatencyPredictor
 
 
@@ -41,31 +41,33 @@ class QoSScheduler:
         self.margin = cfg.safety
         self.violations = 0
         self.rounds = 0
-        self.decisions: List[RoundDecision] = []
 
     def pick(self, bs: int, mean_ctx: float, *, ft_ready: bool,
              ft_units_available: int) -> RoundDecision:
         """Select the finetune quantum for the next decode round."""
+        with obs.span("sched.pick", bs=bs) as sp:
+            d = self._pick(bs, mean_ctx, ft_ready, ft_units_available)
+            sp.set(k=d.k, predicted_s=d.predicted_s, reason=d.reason)
+        return d
+
+    def _pick(self, bs: int, mean_ctx: float, ft_ready: bool,
+              ft_units_available: int) -> RoundDecision:
         self.rounds += 1
         if bs == 0:
             # no decode work: finetune free-runs (max units per round)
             k = min(self.cfg.k_max, ft_units_available) if ft_ready else 0
-            d = RoundDecision(k, 0.0, "idle")
-        elif not ft_ready or ft_units_available <= 0:
-            d = RoundDecision(0, self.pred.predict_colo(0.0, bs, mean_ctx),
-                              "stalled")
-        else:
-            budget = self.cfg.qos_s * self.margin
-            k_best, pred_best = 0, self.pred.predict_colo(0.0, bs, mean_ctx)
-            for k in range(min(self.cfg.k_max, ft_units_available), 0, -1):
-                p = self.pred.predict_colo(k / self.cfg.k_max, bs, mean_ctx)
-                if p <= budget:
-                    k_best, pred_best = k, p
-                    break
-            d = RoundDecision(k_best, pred_best,
-                              "ok" if k_best > 0 else "qos")
-        self.decisions.append(d)
-        return d
+            return RoundDecision(k, 0.0, "idle")
+        if not ft_ready or ft_units_available <= 0:
+            return RoundDecision(0, self.pred.predict_colo(0.0, bs, mean_ctx),
+                                 "stalled")
+        budget = self.cfg.qos_s * self.margin
+        k_best, pred_best = 0, self.pred.predict_colo(0.0, bs, mean_ctx)
+        for k in range(min(self.cfg.k_max, ft_units_available), 0, -1):
+            p = self.pred.predict_colo(k / self.cfg.k_max, bs, mean_ctx)
+            if p <= budget:
+                k_best, pred_best = k, p
+                break
+        return RoundDecision(k_best, pred_best, "ok" if k_best > 0 else "qos")
 
     def observe(self, actual_s: float) -> None:
         """Feedback from the finished round: tighten the margin on QoS

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs import get_config, smoke_config
 from repro.core.colocation import ColocatedRunner
 from repro.core.costmodel import CostModel, InstanceSpec
@@ -180,12 +181,17 @@ def main():
     full = model_config(args.arch, args.smoke).num_layers
     print(f"arch={res.cfg.name} layers={res.cfg.num_layers} of {full} "
           f"rounds={m.decode_rounds} tokens={m.tokens_out} "
-          f"prefills={m.prefills} setup={res.setup_s:.1f}s "
-          f"wall={res.wall_s:.1f}s")
+          f"prefills={m.prefills} rejected={m.rejected_admissions} "
+          f"setup={res.setup_s:.1f}s wall={res.wall_s:.1f}s")
     if res.runner is not None:
         print(f"colocated finetune units executed: {res.units_done} "
               f"(iterations: {int(res.ft_state['iter'])}, "
               f"last loss: {float(res.ft_state['last_loss']):.4f})")
+    print(f"{'span':<26}{'count':>8}{'total s':>10}{'p50 ms':>10}"
+          f"{'p95 ms':>10}")
+    for name, s in obs.summary().items():
+        print(f"{name:<26}{s['count']:>8}{s['total_s']:>10.3f}"
+              f"{s['p50_ms']:>10.3f}{s['p95_ms']:>10.3f}")
 
 
 if __name__ == "__main__":

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import model as MD
 from repro.models.config import ModelConfig
 from repro.serving.kv_cache import PageTableManager, spec_for
@@ -30,7 +31,6 @@ class EngineMetrics:
     tokens_out: int = 0
     prefills: int = 0
     rejected_admissions: int = 0
-    round_batch_sizes: List[int] = dataclasses.field(default_factory=list)
 
 
 class ServingEngine:
@@ -57,40 +57,58 @@ class ServingEngine:
         # cache entries each slot's prefill wrote: frontend + prompt tokens
         self.prefilled = np.zeros((max_slots,), np.int32)
 
-        self._prefill = jax.jit(
-            lambda p, b, c: MD.prefill(p, cfg, b, c))
+        # named for the programs they show as in a profile: jit_prefill,
+        # jit__insert_slot, jit_decode
+        def prefill(p, b, c):
+            return MD.prefill(p, cfg, b, c)
+
+        def decode(p, t, q, c):
+            return MD.decode_step(p, cfg, t, q, c, use_kernels=use_kernels)
+
+        self._prefill = jax.jit(prefill)
         self._insert = jax.jit(_insert_slot, donate_argnums=0)
-        self._decode = jax.jit(
-            lambda p, t, q, c: MD.decode_step(p, cfg, t, q, c,
-                                              use_kernels=use_kernels))
+        self._decode = jax.jit(decode)
 
     # ------------------------------------------------------------- admit --
     def try_admit(self, req: Request, prompt_tokens: np.ndarray,
                   extras: Optional[Dict] = None) -> bool:
-        slot = next((i for i, s in enumerate(self.slots) if s is None), None)
-        n_front = (len(extras["frontend"]) if extras and "frontend" in extras
-                   and self.cfg.frontend != "none" else 0)
-        if slot is None or not self.pages.admit(slot,
-                                                n_front + req.prompt_len):
-            self.metrics.rejected_admissions += 1
-            return False
-        req.slot, req.phase = slot, Phase.PREFILLING
-        self.slots[slot] = req
-        self.prefilled[slot] = n_front + req.prompt_len
-        batch = {"tokens": jnp.asarray(prompt_tokens[None, :])}
-        if extras:
-            batch.update({k: jnp.asarray(v)[None] for k, v in extras.items()})
-        one_cache = MD.init_cache(self.cfg, 1, self.s_max,
-                                  enc_len=self.enc_len)
-        logits, one_cache = self._prefill(self.params, batch, one_cache)
-        self.cache = self._insert(self.cache, one_cache, jnp.int32(slot))
-        tok = int(jnp.argmax(logits[0]))
-        self.last_token[slot] = tok
-        req.generated = 1
-        req.phase = Phase.DECODING
-        self.metrics.prefills += 1
-        self.metrics.tokens_out += 1
-        return True
+        with obs.span("engine.admit", rid=req.rid,
+                      prompt_len=req.prompt_len) as sp:
+            slot = next((i for i, s in enumerate(self.slots) if s is None),
+                        None)
+            n_front = (len(extras["frontend"]) if extras and "frontend" in
+                       extras and self.cfg.frontend != "none" else 0)
+            if slot is None or not self.pages.admit(slot,
+                                                    n_front + req.prompt_len):
+                self.metrics.rejected_admissions += 1
+                sp.set(admitted=False)
+                return False
+            req.slot, req.phase = slot, Phase.PREFILLING
+            self.slots[slot] = req
+            self.prefilled[slot] = n_front + req.prompt_len
+            with obs.span("engine.admit.cache"):
+                one_cache = MD.init_cache(self.cfg, 1, self.s_max,
+                                          enc_len=self.enc_len)
+            with obs.span("engine.admit.prefill"):
+                batch = {"tokens": jnp.asarray(prompt_tokens[None, :])}
+                if extras:
+                    batch.update({k: jnp.asarray(v)[None]
+                                  for k, v in extras.items()})
+                logits, one_cache = self._prefill(self.params, batch,
+                                                  one_cache)
+            with obs.span("engine.admit.insert"):
+                self.cache = self._insert(self.cache, one_cache,
+                                          jnp.int32(slot))
+            with obs.span("engine.admit.first_token"):
+                # the host waits here for the prefill and the insert
+                tok = int(jnp.argmax(logits[0]))
+            self.last_token[slot] = tok
+            req.generated = 1
+            req.phase = Phase.DECODING
+            self.metrics.prefills += 1
+            self.metrics.tokens_out += 1
+            sp.set(admitted=True)
+            return True
 
     # ------------------------------------------------------------- rounds --
     def active_requests(self) -> List[Request]:
@@ -109,19 +127,30 @@ class ServingEngine:
             return {}
         if step is None:
             step = functools.partial(self._decode, self.params)
-        tokens = jnp.asarray(self.last_token)
-        positions = np.zeros((self.max_slots,), np.int32)
-        for i, r in active:
-            # the newest token (not yet in the cache) goes right after the
-            # prefilled entries and the generated - 1 decoded before it, so
-            # each slot's cache stays contiguous from 0
-            positions[i] = self.prefilled[i] + r.generated - 1
-        logits, self.cache = step(tokens, jnp.asarray(positions), self.cache)
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        with obs.span("engine.round", bs=len(active)):
+            with obs.span("engine.round.inputs"):
+                tokens = jnp.asarray(self.last_token)
+                positions = np.zeros((self.max_slots,), np.int32)
+                for i, r in active:
+                    # the newest token (not yet in the cache) goes right
+                    # after the prefilled entries and the generated - 1
+                    # decoded before it, so each slot's cache stays
+                    # contiguous from 0
+                    positions[i] = self.prefilled[i] + r.generated - 1
+                positions = jnp.asarray(positions)
+            with obs.span("engine.round.step"):
+                logits, self.cache = step(tokens, positions, self.cache)
+            with obs.span("engine.round.pull"):
+                # the host waits here for the round's device work
+                next_tokens = np.asarray(jnp.argmax(logits, axis=-1),
+                                         np.int32)
+            with obs.span("engine.round.commit"):
+                return self._commit(active, next_tokens)
 
+    def _commit(self, active, next_tokens) -> Dict[int, int]:
+        """Page extends, token bookkeeping and releases after a round."""
         out: Dict[int, int] = {}
         self.metrics.decode_rounds += 1
-        self.metrics.round_batch_sizes.append(len(active))
         for i, r in active:
             if not self.pages.extend(r.slot, 1):
                 continue  # memory pressure: request stalls this round

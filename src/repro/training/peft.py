@@ -270,7 +270,17 @@ def make_unit_step(cfg: ModelConfig, pc: PeftConfig, params):
         state["iter"] = state["iter"] + 1
         return state
 
-    branches = [u_embed, u_fwd, u_head, u_bwd, u_embed_bwd, u_opt]
+    def scoped(kind, unit):
+        # names the unit's ops ft_unit.<kind> in the program's metadata,
+        # where a profile can tell the kinds apart
+        def run(state):
+            with jax.named_scope(f"ft_unit.{kind}"):
+                return unit(state)
+        return run
+
+    branches = [scoped(kind, unit) for kind, unit in (
+        ("embed", u_embed), ("fwd", u_fwd), ("head", u_head),
+        ("bwd", u_bwd), ("embed_bwd", u_embed_bwd), ("opt", u_opt))]
 
     def branch_id(unit_idx):
         u = unit_idx % upm

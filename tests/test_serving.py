@@ -1,7 +1,10 @@
 """Serving substrate: engine continuous batching, page-table manager,
 trace generator statistics."""
 
+import time
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -10,12 +13,16 @@ try:
 except ImportError:                          # tier-1 container has none
     from _hyp_fallback import given, settings, strategies as st
 
+from repro import obs
+from repro.core.colocation import ColocatedRunner
 from repro.models import model as MD
-from repro.models.config import ModelConfig
+from repro.models.config import LoRAConfig, ModelConfig
 from repro.serving.engine import ServingEngine
 from repro.serving.kv_cache import PagePoolSpec, PageTableManager
 from repro.serving.request import Request
 from repro.serving.trace import TraceConfig, controlled_load, generate
+from repro.training import peft as P
+from repro.training.data import DataConfig, Prefetcher, SyntheticCorpus
 
 
 @pytest.fixture(scope="module")
@@ -31,11 +38,61 @@ def test_engine_continuous_batching(tiny):
     eng = ServingEngine(cfg, params, max_slots=4, s_max=64)
     reqs = [Request(rid=i, arrival=i * 0.01, prompt_len=8 + i,
                     max_new_tokens=6) for i in range(6)]
+    t0 = time.perf_counter()
     m = eng.run_trace(reqs)
+    rounds = [r for r in obs.records(t0) if r.name == "engine.round"]
     assert m.prefills == 6
     assert m.tokens_out == 6 * 6
-    assert max(m.round_batch_sizes) == 4        # slots saturate
+    assert len(rounds) == m.decode_rounds
+    assert max(r.attrs["bs"] for r in rounds) == 4        # slots saturate
     assert all(r.phase.value == "done" for r in reqs)
+
+
+def test_engine_and_colocated_round_spans():
+    """An admission and a co-located round each leave one span with its
+    steps as children, in order; the runner's span names its quantum."""
+    cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=64,
+                      num_heads=4, num_kv_heads=2, d_ff=96, vocab_size=128,
+                      lora=LoRAConfig(rank=4))
+    key = jax.random.PRNGKey(0)
+    params = MD.init_params(cfg, key)
+    pc = P.PeftConfig(micro_batch=2, seq_len=8, accum=1)
+    pf = Prefetcher(SyntheticCorpus(DataConfig(128, 8, 2)).batches(), 2)
+    ft = P.init_ft_state(cfg, pc, params, key, pf.stacked())
+    eng = ServingEngine(cfg, params, max_slots=2, s_max=32)
+    runner = ColocatedRunner(cfg, params, cfg, params, pc, k_max=2)
+    tok = jnp.zeros((2,), jnp.int32)
+    runner.precompile(tok, tok, eng.cache, ft, ks=[2])
+
+    def step(tokens, positions, cache):
+        nonlocal ft
+        logits, cache, ft = runner.run_round(2, tokens, positions, cache, ft)
+        return logits, cache
+
+    t0 = time.perf_counter()
+    req = Request(rid=41, arrival=0.0, prompt_len=6, max_new_tokens=4)
+    assert eng.try_admit(req, np.arange(6, dtype=np.int32))
+    out = eng.decode_round(step)
+    recs = obs.records(t0)
+    assert set(out) == {41}
+
+    def children(parent):
+        kids = [r for r in recs if r.parent == parent.name
+                and parent.t0 <= r.t0 and r.t1 <= parent.t1]
+        return [r.name for r in sorted(kids, key=lambda r: r.t0)]
+
+    (admit,) = [r for r in recs if r.name == "engine.admit"]
+    assert admit.attrs == {"rid": 41, "prompt_len": 6, "admitted": True}
+    assert children(admit) == ["engine.admit.cache", "engine.admit.prefill",
+                               "engine.admit.insert",
+                               "engine.admit.first_token"]
+    (rnd,) = [r for r in recs if r.name == "engine.round"]
+    assert rnd.attrs == {"bs": 1} and rnd.t0 >= admit.t1
+    assert children(rnd) == ["engine.round.inputs", "engine.round.step",
+                             "engine.round.pull", "engine.round.commit"]
+    (colo,) = [r for r in recs if r.name == "colo.round"]
+    assert colo.attrs == {"k": 2} and colo.parent == "engine.round.step"
+    assert not [r for r in recs if r.name == "colo.compile"]
 
 
 def _assert_no_holes(cfg, params, n_front):
@@ -77,7 +134,12 @@ def test_engine_memory_pressure_rejects(tiny):
     ok = eng.try_admit(r, np.arange(60, dtype=np.int32) % 256)
     assert ok
     r2 = Request(rid=1, arrival=0.0, prompt_len=60, max_new_tokens=4)
+    t0 = time.perf_counter()
     assert not eng.try_admit(r2, np.arange(60, dtype=np.int32) % 256)
+    assert eng.metrics.rejected_admissions == 1
+    (rec,) = obs.records(t0)
+    assert rec.name == "engine.admit" and rec.attrs == {
+        "rid": 1, "prompt_len": 60, "admitted": False}
 
 
 # ------------------------------------------------------- page tables ------
