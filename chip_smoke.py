@@ -111,7 +111,7 @@ def fail(msg: str) -> None:
 
 
 def attn_f32(q, kc, vc, kv_pos, positions, window=0, scale=None,
-             scales=None):
+             scales=None, layer=None):
     """decode_attn_ref on float32 copies of its inputs: no bf16 rounding of
     the softmax weights. The TPU's default precision for a float32 matmul
     rounds its inputs to bf16, which would make this the reference again,
@@ -119,7 +119,8 @@ def attn_f32(q, kc, vc, kv_pos, positions, window=0, scale=None,
     f32 = lambda x: x.astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
         return decode_attn_ref(f32(q), f32(kc), f32(vc), kv_pos, positions,
-                               window, scale=scale).astype(q.dtype)
+                               window, scale=scale, layer=layer
+                               ).astype(q.dtype)
 
 
 def kernel_vs_reference(res):

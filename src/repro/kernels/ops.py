@@ -35,18 +35,24 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
 
 
 def decode_attention(q, kc, vc, kv_pos, positions, window: int = 0,
-                     scale=None, page_tokens: int = 64, scales=None):
+                     scale=None, page_tokens: int = 64, scales=None,
+                     layer=None):
     """Dense-cache adapter matching attention.decode_attn_ref's signature so
     model.decode_step can swap the kernel in: treats each slot's contiguous
     cache as pages of `page_tokens`. A full-attention slot holds positions
     0..positions[b] in order, so its valid length is positions + 1.
 
-    q: (B, H, hd); kc/vc: (B, S, KV, hd); kv_pos: (B, S); positions: (B,).
+    q: (B, H, hd); kc/vc: (B, S, KV, hd), or with `layer` a layer scan's
+    stacked cache (L, B, S, KV, hd): the whole stack becomes one pool of
+    pages (merging major dims only, so no copy) and the page table points
+    into layer `layer`'s part of it. kv_pos: unread; positions: (B,).
     Raises on what the kernel cannot take (no silent reference fallback):
     ring-buffered windowed caches, a cache length that is not a multiple of
     `page_tokens`, and int8 caches with per-token scales.
     """
-    B, S, KV, hd = kc.shape
+    if layer is None:
+        kc, vc, layer = kc[None], vc[None], 0
+    L, B, S, KV, hd = kc.shape
     if window > 0:
         raise ValueError(
             f"decode_attention kernel has no sliding-window variant "
@@ -60,9 +66,10 @@ def decode_attention(q, kc, vc, kv_pos, positions, window: int = 0,
                          "int8 caches with per-token scales use "
                          "decode_attn_ref")
     n_pages = S // page_tokens
-    k_pages = kc.reshape(B * n_pages, page_tokens, KV, hd)
-    v_pages = vc.reshape(B * n_pages, page_tokens, KV, hd)
-    page_table = jnp.arange(B * n_pages, dtype=jnp.int32).reshape(B, n_pages)
+    k_pages = kc.reshape(L * B * n_pages, page_tokens, KV, hd)
+    v_pages = vc.reshape(L * B * n_pages, page_tokens, KV, hd)
+    page_table = layer * (B * n_pages) + jnp.arange(
+        B * n_pages, dtype=jnp.int32).reshape(B, n_pages)
     lengths = positions + 1
     return _da.paged_decode_attention(q, k_pages, v_pages, page_table,
                                       lengths, scale=scale,

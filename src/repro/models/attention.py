@@ -155,24 +155,27 @@ def attn_prefill(p: Params, x, positions, cfg: ModelConfig, *,
     return out, new_cache
 
 
-def _cache_write_bulk(cache, k, v, positions, window):
-    """Write a token chunk into the cache (ring-buffered when windowed)."""
-    S_max = cache["k"].shape[1]
+def _cache_write_bulk(cache, k, v, positions, window, layer=None):
+    """Write a token chunk into the cache (ring-buffered when windowed).
+    With ``layer`` the cache leaves carry a leading layer axis and the chunk
+    goes into that layer, in place."""
+    S_max = cache["kv_pos"].shape[-1]
     slots = positions % S_max if window else positions
     B = k.shape[0]
     bidx = jnp.arange(B)[:, None]
+    at = (bidx, slots) if layer is None else (layer, bidx, slots)
     out = dict(cache)
     if "k_scale" in cache:                       # int8 KV
         kq, ks = _quantize_tok(k)
         vq, vs = _quantize_tok(v)
-        out["k"] = cache["k"].at[bidx, slots].set(kq)
-        out["v"] = cache["v"].at[bidx, slots].set(vq)
-        out["k_scale"] = cache["k_scale"].at[bidx, slots].set(ks)
-        out["v_scale"] = cache["v_scale"].at[bidx, slots].set(vs)
+        out["k"] = cache["k"].at[at].set(kq)
+        out["v"] = cache["v"].at[at].set(vq)
+        out["k_scale"] = cache["k_scale"].at[at].set(ks)
+        out["v_scale"] = cache["v_scale"].at[at].set(vs)
     else:
-        out["k"] = cache["k"].at[bidx, slots].set(k.astype(cache["k"].dtype))
-        out["v"] = cache["v"].at[bidx, slots].set(v.astype(cache["v"].dtype))
-    out["kv_pos"] = cache["kv_pos"].at[bidx, slots].set(positions)
+        out["k"] = cache["k"].at[at].set(k.astype(cache["k"].dtype))
+        out["v"] = cache["v"].at[at].set(v.astype(cache["v"].dtype))
+    out["kv_pos"] = cache["kv_pos"].at[at].set(positions)
     return out
 
 
@@ -248,8 +251,12 @@ def _ring_quant_fallback(cache, kq, k_sc, vq, v_sc, positions, window):
 
 def attn_decode(p: Params, x, positions, cache: Dict, cfg: ModelConfig, *,
                 window: int = 0, lora=None, lora_scale: float = 0.0,
-                decode_attn_fn: Optional[Callable] = None):
+                decode_attn_fn: Optional[Callable] = None, layer=None):
     """One-token decode. x: (B, 1, d); positions: (B,). Returns (out, cache).
+
+    With ``layer`` the cache is a layer scan's stacked cache, every leaf with
+    a leading layer axis: the token is written at [layer, b, positions[b]] in
+    place and ``decode_attn_fn`` reads layer ``layer`` of the stack.
 
     Sharding note: the KV cache is SEQUENCE-sharded on the model axis (SPMD
     flash-decode). q/k/v for the new token are tiny, so they are kept
@@ -263,23 +270,31 @@ def attn_decode(p: Params, x, positions, cache: Dict, cfg: ModelConfig, *,
     v = constrain(v, ("batch", None, None, None))
     q = L.apply_rope(q, positions[:, None], cfg.rope_theta)
     k = L.apply_rope(k, positions[:, None], cfg.rope_theta)
-    cache = _cache_write_bulk(cache, k, v, positions[:, None], window)
+    cache = _cache_write_bulk(cache, k, v, positions[:, None], window, layer)
     kc, vc, kv_pos = cache["k"], cache["v"], cache["kv_pos"]
     if decode_attn_fn is None:
         decode_attn_fn = decode_attn_ref
     o = decode_attn_fn(q[:, 0], kc, vc, kv_pos, positions, window,
-                       scales=(cache.get("k_scale"), cache.get("v_scale")))
+                       scales=(cache.get("k_scale"), cache.get("v_scale")),
+                       layer=layer)
     out = _out_proj(p, o[:, None], cfg, lora, lora_scale)
     return out, cache
 
 
 def decode_attn_ref(q, kc, vc, kv_pos, positions, window: int = 0,
-                    scale: Optional[float] = None, scales=None):
-    """Dense decode attention oracle. q: (B, H, hd); cache (B, S, KV, hd).
+                    scale: Optional[float] = None, scales=None, layer=None):
+    """Dense decode attention oracle. q: (B, H, hd); cache (B, S, KV, hd),
+    or with ``layer`` a stacked cache (L, B, S, KV, hd) of which it reads
+    that layer.
 
     int8 caches (scales=(k_scale, v_scale), per-token f32): the dequant
     scales fold into the scores / softmax weights — the cache itself is
     never dequantized to a wide buffer."""
+    if layer is not None:
+        kc, vc, kv_pos, scales = jax.tree.map(
+            lambda t: jax.lax.dynamic_index_in_dim(t, layer, 0,
+                                                   keepdims=False),
+            (kc, vc, kv_pos, scales))
     B, H, hd = q.shape
     KV = kc.shape[2]
     g = H // KV

@@ -208,8 +208,12 @@ def init_cache(cfg: ModelConfig, batch: int, s_max: int, enc_len: int = 0,
 def apply_layer(lp: Params, x, positions, cfg: ModelConfig, kind: str, *,
                 mode: str,                       # "full" | "prefill" | "decode"
                 cache=None, lora=None, scale: float = 0.0,
-                enc_out=None, decode_attn_fn=None, use_kernels=False):
-    """Returns (x, new_cache, aux_loss)."""
+                enc_out=None, decode_attn_fn=None, use_kernels=False,
+                layer=None):
+    """Returns (x, new_cache, aux_loss).
+
+    ``layer``: in decode, the index of this layer in a stacked cache whose
+    full-attention K/V caches keep their leading layer axis (decode_step)."""
     aux = jnp.zeros((), jnp.float32)
     if kind == "hybrid_block":
         new_cache = {} if cache is not None else None
@@ -220,7 +224,7 @@ def apply_layer(lp: Params, x, positions, cfg: ModelConfig, kind: str, *,
                 lp[f"sub{i}"], x, positions, cfg, sub, mode=mode, cache=c,
                 lora=None if lora is None else lora.get(f"sub{i}"),
                 scale=scale, decode_attn_fn=decode_attn_fn,
-                use_kernels=use_kernels)
+                use_kernels=use_kernels, layer=layer)
             if new_cache is not None:
                 new_cache[f"sub{i}"] = nc
             aux += a
@@ -275,7 +279,8 @@ def apply_layer(lp: Params, x, positions, cfg: ModelConfig, kind: str, *,
         self_cache = cache["self"] if kind == "dec" else cache
         attn_out, nc_self = A.attn_decode(
             lp["attn"], h, positions, self_cache, cfg, window=window,
-            lora=lora_d, lora_scale=scale, decode_attn_fn=decode_attn_fn)
+            lora=lora_d, lora_scale=scale, decode_attn_fn=decode_attn_fn,
+            layer=layer)
         nc = {"self": nc_self, "xk": cache["xk"], "xv": cache["xv"]} \
             if kind == "dec" else nc_self
     else:
@@ -549,17 +554,30 @@ def decode_step(params, cfg: ModelConfig, tokens, positions, cache, *,
                                use_kernels=use_kernels)
         new_cache["pre"].append(nc)
 
-    # cache as in-place-updated carry (see prefill note)
+    # cache as in-place-updated carry (see prefill note). A full-attention
+    # K/V cache stays stacked: attn_decode writes the new token at
+    # [i, b, positions[b]] of the carry and reads layer i from the stack, where
+    # slicing the layer out and writing it back would copy its whole K and V
+    # twice a round. Recurrent state, MLA latents and ring-buffered windows
+    # are sliced out and written back.
+    in_place = _layer_window(cfg, scan_kind) == 0
+
+    def stacked(c):
+        return in_place and isinstance(c, dict) and "k" in c and "kv_pos" in c
+
     def body(carry, lp):
         h, cstack, i = carry
-        lc = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
-            t, i, 0, keepdims=False), cstack)
+        lc = jax.tree.map(lambda t: t if stacked(t) else
+                          jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
+                          cstack, is_leaf=stacked)
         h, nc, _ = apply_layer(lp, h, positions, cfg, scan_kind, mode="decode",
                                cache=lc, decode_attn_fn=decode_attn_fn,
-                               use_kernels=use_kernels)
+                               use_kernels=use_kernels,
+                               layer=i if in_place else None)
         cstack = jax.tree.map(
-            lambda t, n: jax.lax.dynamic_update_index_in_dim(
-                t, n.astype(t.dtype), i, 0), cstack, nc)
+            lambda t, n: n if stacked(t) else
+            jax.lax.dynamic_update_index_in_dim(t, n.astype(t.dtype), i, 0),
+            cstack, nc, is_leaf=stacked)
         return (h, cstack, i + 1), None
 
     (x, scan_cache, _), _ = jax.lax.scan(

@@ -67,7 +67,9 @@ class ServingEngine:
 
         self._prefill = jax.jit(prefill)
         self._insert = jax.jit(_insert_slot, donate_argnums=0)
-        self._decode = jax.jit(decode)
+        # the cache is donated, as to _insert: decode writes each new token
+        # into it in place, where a kept input would cost a whole copy
+        self._decode = jax.jit(decode, donate_argnums=3)
 
     # ------------------------------------------------------------- admit --
     def try_admit(self, req: Request, prompt_tokens: np.ndarray,

@@ -1,6 +1,8 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode,
 plus hypothesis-driven paged layouts."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,14 +65,17 @@ def test_paged_decode_attention_hypothesis(B, npg, ptok, seed):
     np.testing.assert_allclose(out, expect, atol=3e-5, rtol=3e-5)
 
 
-def test_decode_step_kernel_matches_reference(key):
+@pytest.mark.parametrize("arch", ["qwen2.5-7b", "qwen3-8b", "llama3-8b"])
+def test_decode_step_kernel_matches_reference(arch, key):
     """model.decode_step through the kernel equals the decode_attn_ref path
-    on the same cache: GQA with 2 q heads per KV head, a cache of two
-    64-token pages, slots that end inside the second page, exactly at its
-    start, and inside the first. Float32 weights and cache, so only the
-    order of the sums differs."""
-    cfg = smoke_config("qwen2.5-7b")
-    assert cfg.q_per_kv > 1
+    on the same cache, for each configuration whose decode takes the kernel
+    (qwen3: qk-norm): GQA with 2 q heads per KV head, a cache of two 64-token
+    pages, slots that end inside the second page, exactly at its start, and
+    inside the first. The kernel reads every layer through the page table
+    of the stacked cache. Float32 weights and cache, so only the order of
+    the sums differs."""
+    cfg = smoke_config(arch)
+    assert cfg.q_per_kv > 1 and cfg.num_layers > 1
     params = MD.init_params(cfg, key, dtype=jnp.float32)
     B, S, plen = 3, 128, 100
     cache = MD.init_cache(cfg, B, S, dtype=jnp.float32)
@@ -82,6 +87,46 @@ def test_decode_step_kernel_matches_reference(key):
            for uk in (True, False)}
     for a, b in zip(jax.tree.leaves(out[True]), jax.tree.leaves(out[False])):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("arch,use_kernels", [
+    ("qwen2.5-7b", True),
+    ("qwen2.5-7b", False),
+    ("mixtral-8x7b", False),       # ring-buffered window: the slice path
+    ("qwen2.5-7b-int8", False),    # int8 K/V with per-token scales
+])
+def test_decode_step_writes_only_the_new_token(arch, use_kernels, key):
+    """A decode step changes exactly the entries [layer, b, slot of
+    positions[b]] of the stacked K/V cache, in every layer and slot, and
+    leaves every other element bit-identical."""
+    int8 = arch.endswith("-int8")
+    cfg = smoke_config(arch.removesuffix("-int8"))
+    if int8:
+        cfg = dataclasses.replace(cfg, kv_quant=True)
+    params = MD.init_params(cfg, key, dtype=jnp.float32)
+    B, S, plen = 3, 128, 40
+    cache = MD.init_cache(cfg, B, S, dtype=jnp.float32)
+    toks = jax.random.randint(key, (B, plen), 0, cfg.vocab_size)
+    _, cache = MD.prefill(params, cfg, {"tokens": toks}, cache)
+    positions = jnp.array([plen, 63, 64], jnp.int32)
+    _, new = MD.decode_step(params, cfg, toks[:, -1], positions, cache,
+                            use_kernels=use_kernels)
+    old, new = cache["scan"], new["scan"]
+    n_layers, _, n_slots = old["kv_pos"].shape
+    assert n_layers > 1
+    assert (n_slots < S) == (cfg.window > 0) and ("k_scale" in old) == int8
+    written = np.zeros(old["kv_pos"].shape, bool)
+    written[:, np.arange(B), np.asarray(positions) % n_slots] = True
+    for name in old:
+        a, b = np.asarray(old[name]), np.asarray(new[name])
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(a[~written], b[~written], err_msg=name)
+        changed = (a[written] != b[written]).reshape(n_layers * B, -1)
+        assert changed.any(axis=1).all(), name
+    np.testing.assert_array_equal(
+        np.asarray(new["kv_pos"])[:, np.arange(B),
+                                  np.asarray(positions) % n_slots],
+        np.broadcast_to(positions, (n_layers, B)))
 
 
 @pytest.mark.parametrize("case", ["unaligned_length", "int8_scales",
